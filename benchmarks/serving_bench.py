@@ -93,6 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model, needs_source
 from repro.serving import (ContinuousBatchingEngine, EngineAuditor,
                            FaultPlan, ServingEngine, Telemetry,
@@ -381,6 +382,7 @@ def main(argv=None) -> int:
                          f"{TELEMETRY_OVERHEAD_MAX_PCT}%%). With multiple "
                          "archs the arch name is appended to the stem")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     archs = [a.strip() for a in args.arch.split(",")]
     results, rc = [], 0

@@ -36,9 +36,15 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      block_size: int = 512,
                      scale: float | None = None,
                      k_scale: jax.Array | None = None,
-                     v_scale: jax.Array | None = None) -> jax.Array:
+                     v_scale: jax.Array | None = None,
+                     interpret: bool = False) -> jax.Array:
     """q: [B, Hq, D]; k_cache/v_cache: [B, S, Hkv, D]; lengths: [B] int32.
     Returns [B, Hq, D]. Hq must be a multiple of Hkv (GQA groups).
+
+    ``impl="kernel"`` compiles the Pallas kernel and so needs a TPU backend;
+    elsewhere it raises unless the caller asks for the Pallas interpreter
+    with ``interpret=True`` (CPU tests). The other impls ignore
+    ``interpret``.
 
     ``ring=True``: the cache is a ring of R = S slots (SWA configs —
     ``window`` required); ``lengths`` counts tokens seen and may exceed S
@@ -108,11 +114,18 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         impl = "blockwise"
 
     if impl == "kernel":
+        backend = jax.default_backend()
+        if backend != "tpu" and not interpret:
+            raise ValueError(
+                f"decode_attention(impl='kernel') compiles a TPU kernel, but "
+                f"the backend is {backend!r}: run on a TPU, or pass "
+                "interpret=True for the Pallas interpreter")
         from repro.kernels.swiftkv_decode import ops as kops
         return kops.swiftkv_decode(q, k_cache, v_cache, lengths,
                                    window=window, ring=ring,
                                    block_k=block_size, scale=scale,
-                                   k_scale=k_scale, v_scale=v_scale)
+                                   k_scale=k_scale, v_scale=v_scale,
+                                   interpret=interpret)
 
     # group queries: [B, Hkv, G, D]; caches to [B, Hkv, S, D]
     qg = q.reshape(b, hkv, g, d)

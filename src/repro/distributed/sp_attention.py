@@ -17,7 +17,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import swiftkv
 from repro.core.swiftkv import SwiftKVState, state_finalize, state_merge
-from repro.distributed.shard_map_compat import pcast, shard_map
 
 
 def _local_partial_state(q, k_loc, v_loc, length, shard_offset, *,
@@ -51,7 +50,7 @@ def _local_partial_state(q, k_loc, v_loc, length, shard_offset, *,
     init = swiftkv.state_init(d, batch_shape=(g,))
     if vary_axes:  # mark the carry as device-varying for shard_map's vma check
         init = jax.tree.map(
-            lambda x: pcast(x, vary_axes, to="varying"), init)
+            lambda x: jax.lax.pcast(x, vary_axes, to="varying"), init)
     return jax.lax.fori_loop(0, n_blocks, body, init)
 
 
@@ -107,7 +106,7 @@ def decode_attention_sp(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     # check_vma=False: after the all-gather + associative merge every seq
     # shard holds the identical value, which the static vma analysis can't
     # infer. Batch stays sharded end to end — the cache never reshards.
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(bd), spec_kv, spec_kv, P(bd)),
         out_specs=P(bd),

@@ -1,10 +1,10 @@
 """Pallas TPU kernel: W4A8 quantized GEMV/GEMM (paper §IV-B).
 
 TPU realization of the SKV Processor Array's low-precision mode: INT4-packed
-weights unpack in VMEM, INT8 activations, INT32 MXU accumulation, group-wise
-(128-input-channel) f32 rescale on the way out. The *same* MXU that runs the
-(f32) attention kernel runs this — the dual-mode-array story, on a TPU
-(DESIGN.md §2).
+weights unpack in VMEM, INT8 activations, int8 x int8 -> INT32 MXU
+accumulation, group-wise (128-input-channel) f32 rescale on the way out.
+The *same* MXU that runs the (f32) attention kernel runs this — the
+dual-mode-array story, on a TPU (DESIGN.md §2).
 
 Grid: ``(M // block_m, N // block_n, K // block_k)`` with sequential K
 (``arbitrary``) accumulating into an f32 VMEM scratch tile (int32 partial
@@ -27,7 +27,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantization import GROUP
-from repro.kernels.pallas_compat import CompilerParams
 
 
 def _kernel(x_ref, wp_ref, xs_ref, ws_ref, o_ref, acc_scr, *, n_k: int,
@@ -38,23 +37,31 @@ def _kernel(x_ref, wp_ref, xs_ref, ws_ref, o_ref, acc_scr, *, n_k: int,
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    packed = wp_ref[...]                                 # [bk, bn//2] uint8
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = (packed >> 4).astype(jnp.int32)
-    lo = jnp.where(lo >= 8, lo - 16, lo)                 # sign-extend nibbles
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    bk = packed.shape[0]
-    w = jnp.stack([lo, hi], axis=-1).reshape(bk, -1)     # [bk, bn] int32
+    # unpack in int32 (no shift runs on uint8), sign-extend, then narrow to
+    # int8 for the MXU: lo nibbles are the even output channels, hi the odd
+    packed = wp_ref[...].astype(jnp.int32)               # [bk, bn//2]
+    lo = packed & 0xF
+    hi = packed >> 4
+    lo = jnp.where(lo >= 8, lo - 16, lo).astype(jnp.int8)
+    hi = jnp.where(hi >= 8, hi - 16, hi).astype(jnp.int8)
 
-    x = x_ref[...].astype(jnp.int32)                     # [bm, bk]
+    x = x_ref[...]                                       # [bm, bk] int8
+    bm, bk = x.shape
     n_groups = bk // group
+    dims = (((1,), (0,)), ((), ()))
     acc = acc_scr[...]
     for g in range(n_groups):                            # static unroll
         sl = slice(g * group, (g + 1) * group)
-        part = jax.lax.dot_general(                      # int32 MXU partials
-            x[:, sl], w[sl, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-        acc = acc + part.astype(jnp.float32) * ws_ref[g, :][None, :]
+        even = jax.lax.dot_general(x[:, sl], lo[sl, :], dims,
+                                   preferred_element_type=jnp.int32)
+        odd = jax.lax.dot_general(x[:, sl], hi[sl, :], dims,
+                                  preferred_element_type=jnp.int32)
+        # interleave the [bm, bn//2] partials back to channel order — a
+        # small relayout, where interleaving the [bk, bn//2] weight tile
+        # would cost a lane-padded copy of the whole tile
+        part = jnp.stack([even, odd], axis=-1).reshape(bm, -1)
+        ws = ws_ref[pl.ds(ik * n_groups + g, 1), :]      # [1, bn] f32
+        acc = acc + part.astype(jnp.float32) * ws
     acc_scr[...] = acc
 
     @pl.when(ik == n_k - 1)
@@ -67,13 +74,20 @@ def gemv_w4a8_pallas(x, w_packed, x_scale, w_scale, *, block_m: int = 8,
                      block_n: int = 256, block_k: int = 512,
                      out_dtype=jnp.float32, interpret: bool = False):
     """x: [M, K] int8; w_packed: [K, N//2] uint8; x_scale: [M, 1] f32;
-    w_scale: [K//GROUP, N] f32. Returns [M, N]. M/N/K multiples of blocks."""
+    w_scale: [K//GROUP, N] f32. Returns [M, N]. M/N/K multiples of blocks.
+
+    The ``w_scale`` block spans every group row, ``(K//GROUP, block_n)``:
+    its first dim is the array's own, so the TPU's (8, 128) tiling rule
+    holds at any K. Its block index does not change along the sequential K
+    axis, so it is fetched once per output tile, and each K step reads its
+    own group rows from VMEM."""
     m, k = x.shape
     n = w_packed.shape[1] * 2
     n_m, n_n, n_k = m // block_m, n // block_n, k // block_k
     assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0
     assert block_k % GROUP == 0
-    groups_per_block = block_k // GROUP
+    n_groups = w_scale.shape[0]
+    assert n_groups == k // GROUP, (n_groups, k)
 
     return pl.pallas_call(
         functools.partial(_kernel, n_k=n_k, group=GROUP),
@@ -82,13 +96,12 @@ def gemv_w4a8_pallas(x, w_packed, x_scale, w_scale, *, block_m: int = 8,
             pl.BlockSpec((block_m, block_k), lambda im, jn, ik: (im, ik)),
             pl.BlockSpec((block_k, block_n // 2), lambda im, jn, ik: (ik, jn)),
             pl.BlockSpec((block_m, 1), lambda im, jn, ik: (im, 0)),
-            pl.BlockSpec((groups_per_block, block_n),
-                         lambda im, jn, ik: (ik, jn)),
+            pl.BlockSpec((n_groups, block_n), lambda im, jn, ik: (0, jn)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda im, jn, ik: (im, jn)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_packed, x_scale, w_scale)

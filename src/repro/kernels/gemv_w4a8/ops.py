@@ -12,19 +12,15 @@ from repro.core.quantization import GROUP, QuantizedLinear, quantize_a8
 from .kernel import gemv_w4a8_pallas
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "out_dtype", "interpret"))
 def gemv_w4a8(x: jax.Array, packed: jax.Array, w_scale: jax.Array,
               *, block_m: int = 8, block_n: int = 256, block_k: int = 512,
-              out_dtype=jnp.float32, interpret: bool | None = None) -> jax.Array:
+              out_dtype=jnp.float32, interpret: bool = False) -> jax.Array:
     """x: [..., K] float; packed: [K, N//2] uint8; w_scale: [K//GROUP, N] f32
     (group-wise, see quantization.quantize_w4). Returns [..., N]. Quantizes
-    activations per-token to int8 (A8)."""
-    interpret = _auto_interpret() if interpret is None else interpret
+    activations per-token to int8 (A8). ``interpret=True`` runs the Pallas
+    interpreter (CPU tests); the default compiles the kernel for the TPU."""
     lead = x.shape[:-1]
     k = x.shape[-1]
     n = packed.shape[1] * 2

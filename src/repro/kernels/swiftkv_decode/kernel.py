@@ -6,11 +6,12 @@ lives in VMEM scratch across sequential grid steps. One pass, exactly-once
 reads, no score materialization, deferred division at the last block — the
 paper's invariants at MXU-friendly granularity.
 
-Grid: ``(B, Hkv, S // block_k)`` — batch and kv-head parallel, KV blocks
-sequential (``arbitrary``). Each program consumes one KV tile for one head
-group: all ``G = Hq/Hkv`` query heads of the group share the single KV read
-(for MQA this amortizes the whole cache scan over 8 query heads — strictly
-better than the paper's per-head duplication).
+Grid: ``(B, S // block_k)`` — batch parallel, KV blocks sequential
+(``arbitrary``). Each program consumes one KV tile holding every KV head of
+``block_k`` positions; inside it each head group's ``G = Hq/Hkv`` query heads
+share that group's single KV read (for MQA this amortizes the whole cache
+scan over 8 query heads — strictly better than the paper's per-head
+duplication).
 
 ``lengths`` rides the scalar-prefetch channel: the KV index map *clamps* block
 fetches past the valid prefix (re-fetching the last valid tile instead of
@@ -31,7 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.exp2_lut import LOG2_E, LUT_SIZE, make_lut
 from repro.core.swiftkv import NEG_INF
-from repro.kernels.pallas_compat import CompilerParams
 
 _LUT_VALS, _LUT_SLOPES = make_lut()
 
@@ -57,14 +57,15 @@ def _exp_lut(x, lut_vals, lut_slopes):
 
 
 def _kernel(lengths_ref,                     # scalar prefetch [B] int32
-            *refs, block_k: int, n_blocks: int, window: int | None,
-            scale: float, exp_mode: str, ring: bool, quant: bool):
+            *refs, block_k: int, n_blocks: int, n_kv: int,
+            window: int | None, scale: float, exp_mode: str, ring: bool,
+            quant: bool):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     refs = refs[3:]
     if quant:
-        # int8 KV: per-(row, head, position) f32 dequant scales arrive as
-        # (1, 1, block_k) tiles through the same clamped kv index map
+        # int8 KV: per-(row, head, position) dequant scales arrive as
+        # (1, Hkv, block_k) tiles through the same clamped kv index map
         ks_ref, vs_ref = refs[:2]
         refs = refs[2:]
     if exp_mode == "lut":
@@ -75,7 +76,7 @@ def _kernel(lengths_ref,                     # scalar prefetch [B] int32
         o_ref, m_scr, z_scr, y_scr = refs
         exp = jnp.exp
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     length = lengths_ref[b]
 
     @pl.when(i == 0)
@@ -86,22 +87,8 @@ def _kernel(lengths_ref,                     # scalar prefetch [B] int32
 
     @pl.when(i * block_k < length)           # blocks past the prefix: no math
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32)              # [G, D]
-        # KV tiles arrive in the cache-native [B, S, Hkv, D] layout —
-        # (1, block_k, 1, D) blocks, no host-side swapaxes/pad copy
-        k = jnp.squeeze(k_ref[...], axis=(0, 2)).astype(jnp.float32)
-        v = jnp.squeeze(v_ref[...], axis=(0, 2)).astype(jnp.float32)
-        if quant:
-            # dequantize in registers: int8 tile x per-position scale —
-            # the cache itself stays int8 in HBM (the 4x byte win); scales
-            # may arrive bf16 (the cache storage dtype) — widen to f32
-            k = k * jnp.squeeze(ks_ref[...], axis=(0, 1)).astype(
-                jnp.float32)[:, None]
-            v = v * jnp.squeeze(vs_ref[...], axis=(0, 1)).astype(
-                jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        slot = i * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        slot = i * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
         if ring:
             # ring cache: slot s holds absolute position p - ((p - s) mod R)
             # for p = length - 1 (R = n_blocks * block_k). Validity comes
@@ -117,24 +104,43 @@ def _kernel(lengths_ref,                     # scalar prefetch [B] int32
             valid = pos < length
             if window is not None:
                 valid &= pos >= length - window
-        s = jnp.where(valid, s, NEG_INF)                 # [G, block_k]
-        valid_f = valid.astype(jnp.float32)
+        valid_f = valid.astype(jnp.float32)              # [1, block_k]
 
-        m_prev = m_scr[...]                              # [G, 128] (lane-bcast)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)       # [G, 1]
-        m_new = jnp.maximum(m_prev, m_blk)               # bcast -> [G, 128]
-        alpha = exp(m_prev - m_new)                      # (0, 1]
-        p = exp(s - m_new[:, :1]) * valid_f              # [G, block_k]
-        z_scr[...] = alpha * z_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        y_scr[...] = alpha[:, :1] * y_scr[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        # the tile holds every KV head of block_k positions in the cache's
+        # native [B, S, Hkv, D] layout; each head group reads its own
+        # (block_k, D) slice, and its G query heads share that one read
+        for h in range(n_kv):                            # static unroll
+            q = q_ref[0, h].astype(jnp.float32)          # [G, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)    # [block_k, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale                                # [G, block_k]
+            if quant:
+                # int8 dequant in VMEM: the per-position K scale multiplies
+                # score column j exactly as it would multiply key row j,
+                # and the V scale folds into p before the PV product below
+                s = s * ks_ref[0, h:h + 1, :].astype(jnp.float32)
+            s = jnp.where(valid, s, NEG_INF)
+
+            m_prev = m_scr[h]                            # [G, 128] lane-bcast
+            m_blk = jnp.max(s, axis=-1, keepdims=True)   # [G, 1]
+            m_new = jnp.maximum(m_prev, m_blk)           # bcast -> [G, 128]
+            alpha = exp(m_prev - m_new)                  # (0, 1]
+            p = exp(s - m_new[:, :1]) * valid_f          # [G, block_k]
+            z_scr[h] = alpha * z_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+            if quant:
+                p = p * vs_ref[0, h:h + 1, :].astype(jnp.float32)
+            y_scr[h] = alpha[:, :1] * y_scr[h] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(i == n_blocks - 1)
     def _finalize():
-        z = z_scr[:, :1]
+        z = z_scr[:, :, :1]
         out = jnp.where(z > 0, y_scr[...] / jnp.where(z > 0, z, 1.0), 0.0)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def swiftkv_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -149,72 +155,74 @@ def swiftkv_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     multiple of block_k); lengths: [B] int32. Returns [B, Hkv, G, D] in
     q.dtype. Feeding the cache layout straight to the grid is what lets the
     ops wrapper stop paying a whole-cache swapaxes+pad copy per layer per
-    decode step. ``ring=True`` consumes a ring cache of R = S slots in
-    place (slot ``s`` holds position ``p - ((p - s) mod R)``, ``p =
-    lengths-1``); only the validity mask changes — the same index maps
-    stream the wrapped cache with zero copies. The unwrapped prefix clamp
-    still applies: while ``lengths <= S`` blocks past the written prefix
-    are neither fetched nor folded.
+    decode step. A KV tile spans all Hkv heads, ``(1, block_k, Hkv, D)``,
+    so its last two dims are the array's own and the TPU's (8, 128) tiling
+    rule holds at any head count and head_dim. ``ring=True`` consumes a
+    ring cache of R = S slots in place (slot ``s`` holds position ``p -
+    ((p - s) mod R)``, ``p = lengths-1``); only the validity mask changes —
+    the same index maps stream the wrapped cache with zero copies. The
+    unwrapped prefix clamp still applies: while ``lengths <= S`` blocks
+    past the written prefix are neither fetched nor folded.
 
     ``k_scale`` / ``v_scale``: optional [B, Hkv, S] float (f32 or bf16)
-    dequant scales for an **int8** cache — streamed as (1, 1, block_k)
-    tiles through the same clamped index map and multiplied into the KV
-    tile in VMEM, so the int8 form adds S x itemsize bytes of scale
-    traffic per (row, head) against the 3 x S x D bytes it saves on the
-    cache itself."""
+    dequant scales for an **int8** cache — streamed as (1, Hkv, block_k)
+    tiles through the same clamped index map and applied in VMEM, so the
+    int8 form adds S x itemsize bytes of scale traffic per (row, head)
+    against the 3 x S x D bytes it saves on the cache itself."""
     bsz, hkv, g, d = q.shape
     s_len = k.shape[1]
     assert s_len % block_k == 0, (s_len, block_k)
     n_blocks = s_len // block_k
     quant = k_scale is not None
 
-    def q_map(b, h, i, lens):
-        return (b, h, 0, 0)
+    def q_map(b, i, lens):
+        return (b, 0, 0, 0)
 
-    def kv_map(b, h, i, lens):
+    def last_block(b, lens):
         # clamp fetches past the valid prefix: no wasted HBM traffic
-        last = jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
-        return (b, jnp.minimum(i, last), h, 0)
+        return jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
 
-    def sc_map(b, h, i, lens):
-        last = jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
-        return (b, h, jnp.minimum(i, last))
+    def kv_map(b, i, lens):
+        return (b, jnp.minimum(i, last_block(b, lens)), 0, 0)
+
+    def sc_map(b, i, lens):
+        return (b, 0, jnp.minimum(i, last_block(b, lens)))
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), q_map),
-        pl.BlockSpec((1, block_k, 1, d), kv_map),
-        pl.BlockSpec((1, block_k, 1, d), kv_map),
+        pl.BlockSpec((1, hkv, g, d), q_map),
+        pl.BlockSpec((1, block_k, hkv, d), kv_map),
+        pl.BlockSpec((1, block_k, hkv, d), kv_map),
     ]
     operands = [q, k, v]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, block_k), sc_map),
-                     pl.BlockSpec((1, 1, block_k), sc_map)]
+        in_specs += [pl.BlockSpec((1, hkv, block_k), sc_map),
+                     pl.BlockSpec((1, hkv, block_k), sc_map)]
         operands += [k_scale, v_scale]
     if exp_mode == "lut":
         lut = jnp.stack([jnp.asarray(_LUT_VALS, jnp.float32),
                          jnp.asarray(_LUT_SLOPES, jnp.float32)])
-        in_specs.append(pl.BlockSpec((2, LUT_SIZE), lambda b, h, i, lens: (0, 0)))
+        in_specs.append(pl.BlockSpec((2, LUT_SIZE), lambda b, i, lens: (0, 0)))
         operands.append(lut)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bsz, hkv, n_blocks),
+        grid=(bsz, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), q_map),
+        out_specs=pl.BlockSpec((1, hkv, g, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),   # mu (lane-broadcast)
-            pltpu.VMEM((g, 128), jnp.float32),   # Z  (lane-broadcast)
-            pltpu.VMEM((g, d), jnp.float32),     # Y
+            pltpu.VMEM((hkv, g, 128), jnp.float32),   # mu (lane-broadcast)
+            pltpu.VMEM((hkv, g, 128), jnp.float32),   # Z  (lane-broadcast)
+            pltpu.VMEM((hkv, g, d), jnp.float32),     # Y
         ],
     )
     kernel = functools.partial(_kernel, block_k=block_k, n_blocks=n_blocks,
-                               window=window, scale=scale, exp_mode=exp_mode,
-                               ring=ring, quant=quant)
+                               n_kv=hkv, window=window, scale=scale,
+                               exp_mode=exp_mode, ring=ring, quant=quant)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, hkv, g, d), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, *operands)

@@ -1,7 +1,8 @@
 """Public jit'd wrapper for the SwiftKV decode kernel.
 
-Handles GQA grouping, block-size selection, and CPU fallback (interpret
-mode) so models can call one function everywhere. KV caches flow through in
+Handles GQA grouping and block-size selection. The kernel is compiled for
+the TPU; interpret mode (the Pallas interpreter, for CPU tests) runs only
+when the caller passes ``interpret=True``. KV caches flow through in
 their native ``[B, S, Hkv, D]`` layout — the kernel's BlockSpec index maps
 tile that layout directly, so there is **no** per-call ``swapaxes`` /
 ``pad`` (the old wrapper copied the entire cache per layer per decode
@@ -19,8 +20,20 @@ import jax.numpy as jnp
 from .kernel import swiftkv_decode_pallas
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# VMEM held by the double-buffered K and V tiles: the kernel's scoped VMEM
+# is 16 MiB on v5e, and the tiles must leave room for in-kernel temporaries
+_KV_TILE_VMEM = 8 << 20
+
+
+def _tile_block_cap(hkv: int, d: int, dtype) -> int:
+    """Largest power-of-two ``block_k`` whose (block_k, Hkv, D) K and V tiles,
+    double-buffered and padded to the TPU tiling ((8 * 4 / itemsize)
+    sublanes by 128 lanes), fit ``_KV_TILE_VMEM``."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    row = -(-hkv // sub) * sub * (-(-d // 128) * 128) * item
+    cap = max(8, _KV_TILE_VMEM // (4 * row))
+    return 1 << (cap.bit_length() - 1)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k", "scale",
@@ -31,7 +44,7 @@ def swiftkv_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                    exp_mode: str = "native", ring: bool = False,
                    k_scale: jax.Array | None = None,
                    v_scale: jax.Array | None = None,
-                   interpret: bool | None = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """SwiftKV single-pass decode attention (Pallas).
 
     q: [B, Hq, D]; k_cache/v_cache: [B, S, Hkv, D]; lengths: [B] int32.
@@ -54,6 +67,11 @@ def swiftkv_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     an int8 cache (``+w4a8`` serving) — streamed blockwise alongside the
     KV tiles and multiplied in VMEM; the alignment contract is unchanged
     (the scale's S axis tiles with the same block size).
+
+    ``block_k`` is also capped so the double-buffered K/V tiles, which span
+    every KV head, fit the kernel's VMEM (``_tile_block_cap``).
+    ``interpret=True`` runs the Pallas interpreter (CPU tests); the default
+    compiles the kernel, which needs a TPU backend.
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("swiftkv_decode: pass both k_scale and v_scale "
@@ -66,9 +84,8 @@ def swiftkv_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     assert hq % hkv == 0
     g = hq // hkv
     scale = float(1.0 / (d ** 0.5)) if scale is None else scale
-    interpret = _auto_interpret() if interpret is None else interpret
 
-    bk = min(block_k, s_len)
+    bk = min(block_k, s_len, _tile_block_cap(hkv, d, k_cache.dtype))
     requested = bk
     if s_len % bk:
         # any power of two at or below (S & -S) divides S exactly

@@ -14,9 +14,13 @@ Usage::
 """
 # The dry-run needs 512 placeholder devices; jax locks the device count on
 # first init, so this MUST precede every other import (including repro.*).
+# It is pinned to the CPU backend: on a machine with a TPU it must neither
+# take the chip nor, through the per-cell child processes (which inherit
+# this environment), contend for it.
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse      # noqa: E402
 import dataclasses   # noqa: E402
@@ -30,6 +34,8 @@ from pathlib import Path  # noqa: E402
 
 import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+
+jax.config.update("jax_platforms", "cpu")   # also when jax came in first
 
 from repro.configs import ARCH_IDS, ASSIGNED_ARCHS, SHAPES, get_config  # noqa: E402
 from repro.distributed import roofline  # noqa: E402
@@ -46,11 +52,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 
 def _cost_dict(cost) -> dict:
-    """Normalize ``cost_analysis()`` across jax releases: 0.4.x returns a
-    one-element list of dicts, newer releases return the dict directly (and
-    either may return None on backends without an analysis)."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
+    """``cost_analysis()`` as a dict (None on backends without one)."""
     return cost if isinstance(cost, dict) else {}
 
 

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.api import build_model, needs_source
 from repro.serving import (ContinuousBatchingEngine, EngineAuditor,
@@ -112,6 +113,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     if args.decode_impl:
         cfg = cfg.replace(decode_impl=args.decode_impl)

@@ -181,8 +181,7 @@ def _moe_apply_ep(p, x, *, top_k, act, gated, capacity_factor, ctx):
     if has_gate:
         operands.append(p["gate"])
         in_specs.append(espec)
-    from repro.distributed.shard_map_compat import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=ctx.mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(bd, None, None), P()),

@@ -794,12 +794,12 @@ class ContinuousBatchingEngine:
                                   jnp.int32(entry))
         self.dispatches += 1
 
-    def _advance_prefills(self) -> None:
-        """One batched dispatch advancing *all* mid-prefill slots one chunk
-        (``prefill_chunks_batched``); finalized requests sample their first
-        token from their chunk-logits row (a scalar int32 transfer, never
-        the [V] logits)."""
-        states = list(self.sched.prefilling)
+    def _prefill_chunk(self, rows) -> tuple[jax.Array, np.ndarray, list]:
+        """One batched prefill dispatch. ``rows``: (slot, prompt, offset)
+        per advancing slot, each prefilling ``prompt[offset:offset+chunk]``.
+        Returns (logits [n_slots, V] — row i meaningful on row i's final
+        chunk —, the offsets, and each row's real token count); the cache
+        is updated in place."""
         n = self.pool.n_slots
         toks = np.full((n, self.chunk), self.pad_id, np.int32)
         slots = np.zeros((n,), np.int32)
@@ -807,20 +807,42 @@ class ContinuousBatchingEngine:
         lasts = np.zeros((n,), np.int32)
         valid = np.zeros((n,), bool)
         sizes = [0] * n
-        for i, st in enumerate(states):
-            prompt = st.request.prompt
-            off = st.prefilled
+        for i, (slot, prompt, off) in enumerate(rows):
             part = prompt[off:off + self.chunk]
             toks[i, :part.size] = part
-            slots[i], offs[i] = st.slot, off
+            slots[i], offs[i] = slot, off
             lasts[i] = min(self.chunk - 1, max(0, len(prompt) - 1 - off))
             valid[i] = True
             sizes[i] = int(part.size)
-        blk_idx = self.prefill_dispatches
-        t_dispatch = time.perf_counter()
         logits, self.cache = self._prefill_batched(
             self.params, jnp.asarray(toks), self.cache, jnp.asarray(slots),
             jnp.asarray(offs), jnp.asarray(lasts), jnp.asarray(valid))
+        return logits, offs, sizes
+
+    def prefill_logits(self, prompt) -> np.ndarray:
+        """Last-token logits [V] (f32) of ``prompt`` through the served
+        chunked-prefill program — the same compiled dispatch ``run`` makes —
+        on slot 0 of an idle engine, which is released afterwards. For
+        checking served numerics against a reference between runs."""
+        if self.pool.n_used or self.needs_source:
+            raise RuntimeError("prefill_logits needs an idle engine and a "
+                               "config without a source")
+        prompt = np.asarray(prompt, np.int32)
+        for off in range(0, len(prompt), self.chunk):
+            logits, _, _ = self._prefill_chunk([(0, prompt, off)])
+        self.cache = self._release(self.cache, jnp.int32(0))
+        return np.asarray(logits[0])
+
+    def _advance_prefills(self) -> None:
+        """One batched dispatch advancing *all* mid-prefill slots one chunk
+        (``prefill_chunks_batched``); finalized requests sample their first
+        token from their chunk-logits row (a scalar int32 transfer, never
+        the [V] logits)."""
+        states = list(self.sched.prefilling)
+        blk_idx = self.prefill_dispatches
+        t_dispatch = time.perf_counter()
+        logits, offs, sizes = self._prefill_chunk(
+            [(st.slot, st.request.prompt, st.prefilled) for st in states])
         self.prefill_dispatches += 1
         self.dispatches += 1
         self.prefill_chunks += len(states)

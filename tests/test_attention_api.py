@@ -24,9 +24,22 @@ def mk(b=2, hq=4, hkv=2, s=96, d=32, dtype=jnp.float32):
 @pytest.mark.parametrize("impl", ["tokenwise", "blockwise", "kernel", "naive"])
 def test_decode_impl_parity(impl):
     q, k, v, lengths = mk()
-    got = attn.decode_attention(q, k, v, lengths, impl=impl, block_size=32)
+    # the kernel runs in the Pallas interpreter here, asked for explicitly
+    got = attn.decode_attention(q, k, v, lengths, impl=impl, block_size=32,
+                                interpret=(impl == "kernel"))
     want = swiftkv_decode_ref(q, k, v, lengths)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_kernel_impl_needs_tpu_or_explicit_interpret():
+    """Off the TPU the compiled kernel cannot run, and the Pallas
+    interpreter runs only when the caller asks for it: no silent
+    fallback."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernel runs here")
+    q, k, v, lengths = mk()
+    with pytest.raises(ValueError, match="interpret=True"):
+        attn.decode_attention(q, k, v, lengths, impl="kernel", block_size=32)
 
 
 @pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2), (8, 1)])  # MHA/GQA/MQA

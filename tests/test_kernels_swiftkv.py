@@ -95,6 +95,18 @@ def test_kernel_small_cache_runs_unpadded():
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+@pytest.mark.parametrize("hkv,d,dtype,cap", [
+    (8, 80, jnp.bfloat16, 512),     # h2o-danube-1.8b: 16 x 128 bf16 rows
+    (8, 128, jnp.int8, 512),        # int8 tiles pad Hkv to 32 sublanes
+    (32, 128, jnp.bfloat16, 256),   # MHA: 512 rows would need 16 MiB
+    (1, 256, jnp.float32, 256),     # MQA: one head still pads to 8 rows
+])
+def test_block_k_cap_fits_kv_tiles_in_vmem(hkv, d, dtype, cap):
+    """A tile spans every KV head, so block_k is capped where the padded,
+    double-buffered K and V tiles would pass 8 MiB of VMEM."""
+    assert ops._tile_block_cap(hkv, d, dtype) == cap
+
+
 def test_kernel_misaligned_cache_raises():
     """The zero-copy contract: a cache whose max_len admits no usable block
     size raises at trace time (allocate block-aligned at init_cache) instead
