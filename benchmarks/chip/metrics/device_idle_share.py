@@ -1,0 +1,7 @@
+"""Device: the share of the traced interval in which no operation ran on the
+chip, in %."""
+
+
+def read(ctx):
+    t = ctx.trace
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"]) if t else None
