@@ -32,6 +32,18 @@ from . import rwkv6 as rwkv_lib
 Params = dict[str, Any]
 Cache = dict[str, Any]
 
+# ``jax.named_scope`` names of the serving programs' sublayers
+# (``decode_step``/``decode_multi`` and ``prefill_chunk``). They change op
+# metadata only: a device trace's ops carry them in their ``tf_op`` path.
+# embed: the token lookup; qkv: ln1, q/k/v projections, qk-norm, rope;
+# kv_write: the cache writes; attention: the attention read; attn_out: wo
+# and its residual; mlp: ln2, the MLP or MoE, and its residual; lm_head:
+# ln_f and the unembedding; sample: the token pick, the finite check and
+# the on-device retirement (decode only). Cross-attention reads and the
+# hybrid family's Mamba branch stay outside every scope.
+SCOPE_NAMES = ("embed", "qkv", "kv_write", "attention", "attn_out", "mlp",
+               "lm_head", "sample")
+
 
 def seeded_gumbel_pick(rng_key: jax.Array, logits: jax.Array,
                        serial: jax.Array, token_idx: jax.Array,
@@ -620,13 +632,14 @@ class TransformerLM:
         cfg = self.cfg
         b, d = h.shape
         dh = cfg.resolved_head_dim
-        q = linear(p, "wq", h).reshape(b, cfg.n_heads, dh)
-        k = linear(p, "wk", h).reshape(b, cfg.n_kv_heads, dh)
-        v = linear(p, "wv", h).reshape(b, cfg.n_kv_heads, dh)
-        if cfg.qk_norm:
-            q = rms_norm(q, p["qn"], cfg.norm_eps)
-            k = rms_norm(k, p["kn"], cfg.norm_eps)
-        q, k = self._rope_qk_decode(cache, q, k, cache["len"])
+        with jax.named_scope("qkv"):
+            q = linear(p, "wq", h).reshape(b, cfg.n_heads, dh)
+            k = linear(p, "wk", h).reshape(b, cfg.n_kv_heads, dh)
+            v = linear(p, "wv", h).reshape(b, cfg.n_kv_heads, dh)
+            if cfg.qk_norm:
+                q = rms_norm(q, p["qn"], cfg.norm_eps)
+                k = rms_norm(k, p["kn"], cfg.norm_eps)
+            q, k = self._rope_qk_decode(cache, q, k, cache["len"])
         ring = bool(cfg.kv_ring and cfg.window)
         write_mask = None
         if active is None:
@@ -646,22 +659,27 @@ class TransformerLM:
             # membership changes (serving/slot_pool.py reserves the tail)
             write_at = jnp.where(active, cache["len"], kc.shape[1] - 1)
             attn_len = jnp.where(active, cache["len"] + 1, 1)
-        if ksc is not None:
-            # int8 cache: quantize the new token's K/V over Dh per head —
-            # the write parks/wraps exactly like the fp path, and the scale
-            # plane parks with it so released rows stay (0, scale 0)
-            k, k_s = quantize_kv(k)
-            v, v_s = quantize_kv(v)
-            ksc, vsc = self._write_kv_scales(ksc, vsc, k_s, v_s,
-                                             write_at, write_mask)
-        kc, vc = self._write_kv(kc, vc, k.astype(kc.dtype), v.astype(vc.dtype),
-                                write_at, write_mask)
-        out = attn_lib.decode_attention(q, kc, vc, attn_len,
-                                        impl=cfg.decode_impl,
-                                        window=cfg.window, ring=ring,
-                                        block_size=cfg.attn_block or 512,
-                                        k_scale=ksc, v_scale=vsc)
-        return linear(p, "wo", out.reshape(b, -1)), kc, vc, ksc, vsc
+        with jax.named_scope("kv_write"):
+            if ksc is not None:
+                # int8 cache: quantize the new token's K/V over Dh per head
+                # — the write parks/wraps exactly like the fp path, and the
+                # scale plane parks with it so released rows stay
+                # (0, scale 0)
+                k, k_s = quantize_kv(k)
+                v, v_s = quantize_kv(v)
+                ksc, vsc = self._write_kv_scales(ksc, vsc, k_s, v_s,
+                                                 write_at, write_mask)
+            kc, vc = self._write_kv(kc, vc, k.astype(kc.dtype),
+                                    v.astype(vc.dtype), write_at, write_mask)
+        with jax.named_scope("attention"):
+            out = attn_lib.decode_attention(q, kc, vc, attn_len,
+                                            impl=cfg.decode_impl,
+                                            window=cfg.window, ring=ring,
+                                            block_size=cfg.attn_block or 512,
+                                            k_scale=ksc, v_scale=vsc)
+        with jax.named_scope("attn_out"):
+            out = linear(p, "wo", out.reshape(b, -1))
+        return out, kc, vc, ksc, vsc
 
     def _decode_cross_attn(self, p: Params, h: jax.Array, ck, cv,
                            source_len: jax.Array) -> jax.Array:
@@ -712,7 +730,8 @@ class TransformerLM:
         tensors; returns updated slices as scan ys."""
         cfg = self.cfg
         new = {}
-        h = rms_norm(x, bp["ln1"], cfg.norm_eps)
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, bp["ln1"], cfg.norm_eps)
         attn_out, new["k"], new["v"], ksc, vsc = self._decode_self_attn(
             bp["attn"], h, slices["k"], slices["v"], cache, active,
             slices.get("k_scale"), slices.get("v_scale"))
@@ -729,7 +748,8 @@ class TransformerLM:
             x = x + 0.5 * (rms_norm(attn_out, bp["ln_attn_out"], cfg.norm_eps)
                            + rms_norm(m_out, bp["ln_mamba_out"], cfg.norm_eps))
         else:
-            x = x + attn_out
+            with jax.named_scope("attn_out"):
+                x = x + attn_out
         if "cross" in bp and "src_k" in slices:
             # pooled source KV (continuous serving): read-only, per-slot
             # entry indirection via cache["src_index"]
@@ -744,18 +764,20 @@ class TransformerLM:
                                             slices["cross_v"],
                                             cache["source_len"])
             new["cross_k"], new["cross_v"] = slices["cross_k"], slices["cross_v"]
-        h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
-        if cfg.n_experts:
-            # capacity-free per-row dispatch at decode: identical math to the
-            # capacity path when nothing drops, but a row's output depends
-            # only on that row — batch composition can't perturb a request
-            # (ragged serving's per-request-equivalence contract), and at
-            # B = n_slots it is also the cheaper form
-            y, _ = moe_lib.moe_apply_rowwise(bp["ffn"], h2, top_k=cfg.top_k,
-                                             act=cfg.act, gated=cfg.gated_mlp)
-        else:
-            y = mlp_apply(bp["ffn"], h2, cfg.act, cfg.gated_mlp)
-        return x + y, new
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
+            if cfg.n_experts:
+                # capacity-free per-row dispatch at decode: identical math to
+                # the capacity path when nothing drops, but a row's output
+                # depends only on that row — batch composition can't perturb
+                # a request (ragged serving's per-request-equivalence
+                # contract), and at B = n_slots it is also the cheaper form
+                y, _ = moe_lib.moe_apply_rowwise(
+                    bp["ffn"], h2, top_k=cfg.top_k, act=cfg.act,
+                    gated=cfg.gated_mlp)
+            else:
+                y = mlp_apply(bp["ffn"], h2, cfg.act, cfg.gated_mlp)
+            return x + y, new
 
     def decode_step(self, params: Params, tokens: jax.Array,
                     cache: Cache, active: jax.Array | None = None
@@ -782,7 +804,8 @@ class TransformerLM:
         state still advances for every row; a slot's state is reseeded by
         ``finalize_slot`` when a new request fills it."""
         cfg = self.cfg
-        x = params["embed"].astype(self._dt)[tokens]             # [B, d]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(self._dt)[tokens]         # [B, d]
 
         if cfg.family == "ssm":
             return self._rwkv_decode_step(params, x, cache, active)
@@ -867,8 +890,9 @@ class TransformerLM:
         cache["len"] = cache["len"] + (1 if active is None
                                        else active.astype(jnp.int32))
         cache = self._advance_rope(cache)
-        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-        return self._unembed(params, x), cache
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+            return self._unembed(params, x), cache
 
     # ---- multi-tick decode: K fused ticks, one dispatch --------------------
     def decode_multi(self, params: Params, tok: jax.Array, cache: Cache,
@@ -931,24 +955,25 @@ class TransformerLM:
         def tick(carry, _):
             tok, cache, active, emitted = carry
             logits, cache = self.decode_step(params, tok, cache, active)
-            if poison is not None:
-                logits = jnp.where(poison[:, None], jnp.nan, logits)
-            finite = jnp.all(jnp.isfinite(logits), axis=-1)
-            pick = pick_tokens(logits, emitted)
-            ok = active & finite
-            emitted = jnp.where(ok, emitted + 1, emitted)
-            done = emitted >= budget
-            if eos_id is not None:
-                done |= pick == eos_id
-            # healthy rows report their token; a non-finite row reports the
-            # -2 quarantine sentinel; inactive rows stay -1
-            out = jnp.where(active,
-                            jnp.where(finite, pick, jnp.int32(-2)),
-                            jnp.int32(-1))
-            active = ok & ~done
-            # a retired row's final token is emitted but never fed back —
-            # exactly the single-tick engine's contract
-            tok = jnp.where(active, pick, tok)
+            with jax.named_scope("sample"):
+                if poison is not None:
+                    logits = jnp.where(poison[:, None], jnp.nan, logits)
+                finite = jnp.all(jnp.isfinite(logits), axis=-1)
+                pick = pick_tokens(logits, emitted)
+                ok = active & finite
+                emitted = jnp.where(ok, emitted + 1, emitted)
+                done = emitted >= budget
+                if eos_id is not None:
+                    done |= pick == eos_id
+                # healthy rows report their token; a non-finite row reports
+                # the -2 quarantine sentinel; inactive rows stay -1
+                out = jnp.where(active,
+                                jnp.where(finite, pick, jnp.int32(-2)),
+                                jnp.int32(-1))
+                active = ok & ~done
+                # a retired row's final token is emitted but never fed back
+                # — exactly the single-tick engine's contract
+                tok = jnp.where(active, pick, tok)
             return (tok, cache, active, emitted), out
 
         (tok, cache, active, emitted), tok_block = jax.lax.scan(
@@ -1232,7 +1257,8 @@ class TransformerLM:
         (c,) = tokens.shape
         dh = cfg.resolved_head_dim
         smax, hkv = cache["k"].shape[2], cfg.n_kv_heads
-        x = params["embed"].astype(self._dt)[tokens][None]       # [1, C, d]
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(self._dt)[tokens][None]   # [1, C, d]
         positions = offset + jnp.arange(c)
         kv_len = jnp.reshape(offset + c, (1,)).astype(jnp.int32)
         q_off = jnp.reshape(offset, (1,)).astype(jnp.int32)
@@ -1273,117 +1299,136 @@ class TransformerLM:
             out = linear(cp, "wo", out.reshape(1, c, -1))
             return jnp.tanh(cp["gate"]).astype(hc.dtype) * out
 
-        def step(x, xs):
-            bp, slices = xs
-            new = {}
-            ap = bp["attn"]
-            h = rms_norm(x, bp["ln1"], cfg.norm_eps)
-            q, k, v = self._qkv_rope(ap, h, positions)
+        def ring_fill(slices, new, k, v):
+            """The chunk's keys and values into this slot's ring: token at
+            absolute position p lands in ring slot p % R (wrap-aware
+            scatter); padded tail rows (> last) keep the old slot value so
+            only real tokens occupy ring slots. An int8 cache quantizes per
+            (position, head) and scatters the scale planes the same way
+            (into ``new``). Returns what the chunk attends — the slot's
+            ring, on an int8 cache dequantized with the chunk's own fresh
+            fp values overlaid (as the full-cache path) — and the updated
+            K/V caches."""
             quant = "k_scale" in slices
+            k_fp, v_fp = k, v
             if quant:
-                # int8 cache: chunk K/V quantize per (position, head); the
-                # chunk then attends *through the cache slot* (unlike full
-                # prefill), so the slot reads below dequantize whole-row.
-                # The current chunk's own positions are overlaid with their
-                # fresh fp values — quantization noise enters a chunk's
-                # attention only through the *already-written* prefix, the
-                # part that is genuinely stored int8 at read time. This is
-                # what keeps single-chunk prompts bit-identical to the
-                # lock-step quantized prefill (which attends fp K/V
-                # throughout) and the measured agreement tier tight.
-                k_fp, v_fp = k, v
                 k, k_s = quantize_kv(k)                  # k_s [1, C, Hkv]
                 v, v_s = quantize_kv(v)
                 k_s = k_s.astype(slices["k_scale"].dtype)
                 v_s = v_s.astype(slices["v_scale"].dtype)
+            idx = jnp.mod(positions, smax)                       # [C]
+            keep = (jnp.arange(c) <= last)[:, None, None]
+            k_slot = jax.lax.dynamic_slice(slices["k"], (slot, 0, 0, 0),
+                                           (1, smax, hkv, dh))
+            v_slot = jax.lax.dynamic_slice(slices["v"], (slot, 0, 0, 0),
+                                           (1, smax, hkv, dh))
+            k_slot = k_slot.at[0, idx].set(
+                jnp.where(keep, k[0].astype(k_slot.dtype), k_slot[0, idx]))
+            v_slot = v_slot.at[0, idx].set(
+                jnp.where(keep, v[0].astype(v_slot.dtype), v_slot[0, idx]))
+            kc = jax.lax.dynamic_update_slice(slices["k"], k_slot,
+                                              (slot, 0, 0, 0))
+            vc = jax.lax.dynamic_update_slice(slices["v"], v_slot,
+                                              (slot, 0, 0, 0))
+            if not quant:
+                return k_slot, v_slot, kc, vc
+            # same keep-masked ring scatter on the scale planes,
+            # position-major for the gather then back to [1, Hkv, R]
+            keep_s = (jnp.arange(c) <= last)[:, None]
+            ks_t = jnp.swapaxes(jax.lax.dynamic_slice(
+                slices["k_scale"], (slot, 0, 0), (1, hkv, smax))[0], 0, 1)
+            vs_t = jnp.swapaxes(jax.lax.dynamic_slice(
+                slices["v_scale"], (slot, 0, 0), (1, hkv, smax))[0], 0, 1)
+            ks_t = ks_t.at[idx].set(jnp.where(keep_s, k_s[0], ks_t[idx]))
+            vs_t = vs_t.at[idx].set(jnp.where(keep_s, v_s[0], vs_t[idx]))
+            new["k_scale"] = jax.lax.dynamic_update_slice(
+                slices["k_scale"], jnp.swapaxes(ks_t, 0, 1)[None],
+                (slot, 0, 0))
+            new["v_scale"] = jax.lax.dynamic_update_slice(
+                slices["v_scale"], jnp.swapaxes(vs_t, 0, 1)[None],
+                (slot, 0, 0))
+            k_att = k_slot.astype(jnp.float32) * ks_t[None, :, :, None]
+            v_att = v_slot.astype(jnp.float32) * vs_t[None, :, :, None]
+            # fresh-fp overlay of the current chunk's ring slots
+            k_att = k_att.at[0, idx].set(
+                jnp.where(keep, k_fp[0].astype(jnp.float32), k_att[0, idx]))
+            v_att = v_att.at[0, idx].set(
+                jnp.where(keep, v_fp[0].astype(jnp.float32), v_att[0, idx]))
+            return k_att, v_att, kc, vc
+
+        def step(x, xs):
+            bp, slices = xs
+            new = {}
+            ap = bp["attn"]
+            with jax.named_scope("qkv"):
+                h = rms_norm(x, bp["ln1"], cfg.norm_eps)
+                q, k, v = self._qkv_rope(ap, h, positions)
             if ring:
-                # ring fill: chunk token at absolute position p lands in
-                # ring slot p % R (wrap-aware scatter); padded tail rows
-                # (> last) keep the old slot value so only real tokens
-                # occupy ring slots
-                idx = jnp.mod(positions, smax)                   # [C]
-                keep = (jnp.arange(c) <= last)[:, None, None]
-                k_slot = jax.lax.dynamic_slice(slices["k"], (slot, 0, 0, 0),
-                                               (1, smax, hkv, dh))
-                v_slot = jax.lax.dynamic_slice(slices["v"], (slot, 0, 0, 0),
-                                               (1, smax, hkv, dh))
-                k_slot = k_slot.at[0, idx].set(
-                    jnp.where(keep, k[0].astype(k_slot.dtype), k_slot[0, idx]))
-                v_slot = v_slot.at[0, idx].set(
-                    jnp.where(keep, v[0].astype(v_slot.dtype), v_slot[0, idx]))
-                kc = jax.lax.dynamic_update_slice(slices["k"], k_slot,
-                                                  (slot, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(slices["v"], v_slot,
-                                                  (slot, 0, 0, 0))
-                k_att, v_att = k_slot, v_slot
-                if quant:
-                    # same keep-masked ring scatter on the scale planes,
-                    # position-major for the gather then back to [1, Hkv, R]
-                    keep_s = (jnp.arange(c) <= last)[:, None]
-                    ks_t = jnp.swapaxes(jax.lax.dynamic_slice(
-                        slices["k_scale"], (slot, 0, 0),
-                        (1, hkv, smax))[0], 0, 1)        # [R, Hkv]
-                    vs_t = jnp.swapaxes(jax.lax.dynamic_slice(
-                        slices["v_scale"], (slot, 0, 0),
-                        (1, hkv, smax))[0], 0, 1)
-                    ks_t = ks_t.at[idx].set(
-                        jnp.where(keep_s, k_s[0], ks_t[idx]))
-                    vs_t = vs_t.at[idx].set(
-                        jnp.where(keep_s, v_s[0], vs_t[idx]))
-                    new["k_scale"] = jax.lax.dynamic_update_slice(
-                        slices["k_scale"], jnp.swapaxes(ks_t, 0, 1)[None],
-                        (slot, 0, 0))
-                    new["v_scale"] = jax.lax.dynamic_update_slice(
-                        slices["v_scale"], jnp.swapaxes(vs_t, 0, 1)[None],
-                        (slot, 0, 0))
-                    k_att = k_slot.astype(jnp.float32) * ks_t[None, :, :, None]
-                    v_att = v_slot.astype(jnp.float32) * vs_t[None, :, :, None]
-                    # fresh-fp overlay of the current chunk's ring slots
-                    k_att = k_att.at[0, idx].set(
-                        jnp.where(keep, k_fp[0].astype(jnp.float32),
-                                  k_att[0, idx]))
-                    v_att = v_att.at[0, idx].set(
-                        jnp.where(keep, v_fp[0].astype(jnp.float32),
-                                  v_att[0, idx]))
-                attn = attn_lib.prefill_attention_ring(
-                    q, k_att, v_att, positions, offset + last,
-                    window=cfg.window)
+                with jax.named_scope("kv_write"):
+                    k_att, v_att, kc, vc = ring_fill(slices, new, k, v)
+                with jax.named_scope("attention"):
+                    attn = attn_lib.prefill_attention_ring(
+                        q, k_att, v_att, positions, offset + last,
+                        window=cfg.window)
             else:
-                kc = jax.lax.dynamic_update_slice(
-                    slices["k"], k.astype(slices["k"].dtype),
-                    (slot, offset, 0, 0))
-                vc = jax.lax.dynamic_update_slice(
-                    slices["v"], v.astype(slices["v"].dtype),
-                    (slot, offset, 0, 0))
-                k_slot = jax.lax.dynamic_slice(kc, (slot, 0, 0, 0),
-                                               (1, smax, hkv, dh))
-                v_slot = jax.lax.dynamic_slice(vc, (slot, 0, 0, 0),
-                                               (1, smax, hkv, dh))
-                if quant:
-                    new["k_scale"] = jax.lax.dynamic_update_slice(
-                        slices["k_scale"], jnp.swapaxes(k_s, 1, 2),
-                        (slot, 0, offset))
-                    new["v_scale"] = jax.lax.dynamic_update_slice(
-                        slices["v_scale"], jnp.swapaxes(v_s, 1, 2),
-                        (slot, 0, offset))
-                    ks_slot = jax.lax.dynamic_slice(
-                        new["k_scale"], (slot, 0, 0), (1, hkv, smax))
-                    vs_slot = jax.lax.dynamic_slice(
-                        new["v_scale"], (slot, 0, 0), (1, hkv, smax))
-                    k_slot = (k_slot.astype(jnp.float32)
-                              * jnp.swapaxes(ks_slot, 1, 2)[..., None])
-                    v_slot = (v_slot.astype(jnp.float32)
-                              * jnp.swapaxes(vs_slot, 1, 2)[..., None])
-                    # fresh-fp overlay of the current chunk's positions
-                    k_slot = jax.lax.dynamic_update_slice(
-                        k_slot, k_fp.astype(jnp.float32), (0, offset, 0, 0))
-                    v_slot = jax.lax.dynamic_update_slice(
-                        v_slot, v_fp.astype(jnp.float32), (0, offset, 0, 0))
-                attn = attn_lib.prefill_attention(
-                    q, k_slot, v_slot, causal=True, window=cfg.window,
-                    kv_lengths=kv_len, q_offset=q_off,
-                    kv_block=cfg.attn_block or 512)
-            attn_out = linear(ap, "wo", attn.reshape(1, c, -1))
+                with jax.named_scope("kv_write"):
+                    k_fp, v_fp = k, v
+                    quant = "k_scale" in slices
+                    if quant:
+                        # int8 cache: chunk K/V quantize per (position,
+                        # head); the scale planes land at the chunk's rows
+                        k, k_s = quantize_kv(k)          # k_s [1, C, Hkv]
+                        v, v_s = quantize_kv(v)
+                        new["k_scale"] = jax.lax.dynamic_update_slice(
+                            slices["k_scale"],
+                            jnp.swapaxes(k_s, 1, 2).astype(
+                                slices["k_scale"].dtype), (slot, 0, offset))
+                        new["v_scale"] = jax.lax.dynamic_update_slice(
+                            slices["v_scale"],
+                            jnp.swapaxes(v_s, 1, 2).astype(
+                                slices["v_scale"].dtype), (slot, 0, offset))
+                    kc = jax.lax.dynamic_update_slice(
+                        slices["k"], k.astype(slices["k"].dtype),
+                        (slot, offset, 0, 0))
+                    vc = jax.lax.dynamic_update_slice(
+                        slices["v"], v.astype(slices["v"].dtype),
+                        (slot, offset, 0, 0))
+                with jax.named_scope("attention"):
+                    k_slot = jax.lax.dynamic_slice(kc, (slot, 0, 0, 0),
+                                                   (1, smax, hkv, dh))
+                    v_slot = jax.lax.dynamic_slice(vc, (slot, 0, 0, 0),
+                                                   (1, smax, hkv, dh))
+                    if quant:
+                        # the chunk attends *through the cache slot* (unlike
+                        # full prefill), so the slot reads dequantize
+                        # whole-row, and the current chunk's own positions
+                        # are overlaid with their fresh fp values —
+                        # quantization noise enters a chunk's attention only
+                        # through the *already-written* prefix, the part
+                        # that is genuinely stored int8 at read time. This
+                        # keeps single-chunk prompts bit-identical to the
+                        # lock-step quantized prefill (which attends fp K/V
+                        # throughout) and the measured agreement tier tight.
+                        ks_slot = jax.lax.dynamic_slice(
+                            new["k_scale"], (slot, 0, 0), (1, hkv, smax))
+                        vs_slot = jax.lax.dynamic_slice(
+                            new["v_scale"], (slot, 0, 0), (1, hkv, smax))
+                        k_slot = (k_slot.astype(jnp.float32)
+                                  * jnp.swapaxes(ks_slot, 1, 2)[..., None])
+                        v_slot = (v_slot.astype(jnp.float32)
+                                  * jnp.swapaxes(vs_slot, 1, 2)[..., None])
+                        k_slot = jax.lax.dynamic_update_slice(
+                            k_slot, k_fp.astype(jnp.float32),
+                            (0, offset, 0, 0))
+                        v_slot = jax.lax.dynamic_update_slice(
+                            v_slot, v_fp.astype(jnp.float32),
+                            (0, offset, 0, 0))
+                    attn = attn_lib.prefill_attention(
+                        q, k_slot, v_slot, causal=True, window=cfg.window,
+                        kv_lengths=kv_len, q_offset=q_off,
+                        kv_block=cfg.attn_block or 512)
+            with jax.named_scope("attn_out"):
+                attn_out = linear(ap, "wo", attn.reshape(1, c, -1))
             new["k"], new["v"] = kc, vc
             if cfg.family == "hybrid":
                 d_inner = cfg.ssm_expand * cfg.d_model
@@ -1406,27 +1451,30 @@ class TransformerLM:
                                + rms_norm(m_out, bp["ln_mamba_out"],
                                           cfg.norm_eps))
             else:
-                x = x + attn_out
+                with jax.named_scope("attn_out"):
+                    x = x + attn_out
             if "cross" in bp and "src_k" in slices:   # whisper-style in-layer
                 hc = rms_norm(x, bp["ln_cross"], cfg.norm_eps)
                 x = x + cross_read(bp["cross"], hc, slices["src_k"],
                                    slices["src_v"],
                                    slices.get("src_k_scale"),
                                    slices.get("src_v_scale"))
-            h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
-            if cfg.n_experts:
-                # capacity = chunk length C: each token assigns an expert at
-                # most once, so per-expert load <= C and nothing can drop —
-                # drop-free capacity dispatch equals the per-row form
-                # exactly, padded positions can't evict real tokens, and the
-                # [E, C, d] queue stays small (the per-row dense gather
-                # would materialize C*k full expert matrices per layer)
-                y, _ = moe_lib.moe_apply(bp["ffn"], h2, top_k=cfg.top_k,
-                                         act=cfg.act, gated=cfg.gated_mlp,
-                                         capacity=c)
-            else:
-                y = mlp_apply(bp["ffn"], h2, cfg.act, cfg.gated_mlp)
-            return x + y, new
+            with jax.named_scope("mlp"):
+                h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
+                if cfg.n_experts:
+                    # capacity = chunk length C: each token assigns an
+                    # expert at most once, so per-expert load <= C and
+                    # nothing can drop — drop-free capacity dispatch equals
+                    # the per-row form exactly, padded positions can't evict
+                    # real tokens, and the [E, C, d] queue stays small (the
+                    # per-row dense gather would materialize C*k full expert
+                    # matrices per layer)
+                    y, _ = moe_lib.moe_apply(bp["ffn"], h2, top_k=cfg.top_k,
+                                             act=cfg.act,
+                                             gated=cfg.gated_mlp, capacity=c)
+                else:
+                    y = mlp_apply(bp["ffn"], h2, cfg.act, cfg.gated_mlp)
+                return x + y, new
 
         self_slices = {"k": cache["k"], "v": cache["v"]}
         if "k_scale" in cache:
@@ -1481,10 +1529,11 @@ class TransformerLM:
         cache = dict(cache)
         for key, val in new.items():
             cache[key] = val
-        x_last = jax.lax.dynamic_slice(x, (0, last, 0),
-                                       (1, 1, cfg.d_model))[:, 0]
-        x_last = rms_norm(x_last, params["ln_f"], cfg.norm_eps)
-        return self._unembed(params, x_last)[0], cache
+        with jax.named_scope("lm_head"):
+            x_last = jax.lax.dynamic_slice(x, (0, last, 0),
+                                           (1, 1, cfg.d_model))[:, 0]
+            x_last = rms_norm(x_last, params["ln_f"], cfg.norm_eps)
+            return self._unembed(params, x_last)[0], cache
 
     def _rwkv_prefill_chunk(self, params: Params, tokens: jax.Array,
                             cache: Cache, slot: jax.Array, last: jax.Array

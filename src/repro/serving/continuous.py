@@ -90,13 +90,22 @@ round-trip collapse measurable, and ``parked_ticks`` (ticks issued minus
 tokens emitted) measures the mid-block-retirement waste the eos-aware
 horizon would recover.
 
-Observability: pass ``telemetry=Telemetry()`` to record the structured
-lifecycle event stream (enqueue/admit/backfill, source pool ledger events,
-prefill_chunk, first_token, decode_block, eos/budget_retire/release) plus
-per-block engine gauges, exportable to Chrome/Perfetto trace format — see
-``repro.serving.telemetry`` and ``docs/serving.md``. Every emission site is
-guarded, so the default (``telemetry=None``) path is the exact
-pre-telemetry host loop: byte-identical tokens, zero events.
+Observability, two layers. Each step's phases are profiler spans, always
+on: ``serve.admit``, ``serve.prefill``, ``serve.first_token`` (per finished
+prompt), ``serve.decode``, ``serve.sync``, ``serve.retire``
+(``repro.serving.telemetry.SPAN_NAMES``). Each is one
+``jax.profiler.TraceAnnotation`` and records nothing unless a profiler
+session is active; under ``jax.profiler.trace`` they share the device
+trace's clock, and the decode and prefill programs' ops carry the model's
+named scopes (``repro.models.transformer.SCOPE_NAMES``). Pass
+``telemetry=Telemetry()`` to also record the structured lifecycle event
+stream (enqueue/admit/backfill, source pool ledger events, prefill_chunk,
+first_token, decode_block, eos/budget_retire/release) plus per-block engine
+gauges, exportable to Chrome/Perfetto trace format — see
+``repro.serving.telemetry`` and ``docs/serving.md``; ``decode_block`` and
+``prefill_chunk`` take their ``dur`` from the span boundaries. Every
+emission site is guarded, so the default (``telemetry=None``) path builds
+no event objects: byte-identical tokens, zero events.
 """
 from __future__ import annotations
 
@@ -114,7 +123,7 @@ from .faults import FaultInjected, FaultPlan
 from .scheduler import (OverloadConfig, Request, RequestState, Scheduler,
                         DECODING, PREFILLING, QUEUED)
 from .slot_pool import KVSlotPool, SourceKVPool
-from .telemetry import LogHistogram, Telemetry
+from .telemetry import LogHistogram, Telemetry, span
 
 
 def _pct(xs, q):
@@ -163,8 +172,8 @@ class ContinuousBatchingEngine:
         self.max_ticks = decode_ticks
         self._t0 = time.perf_counter()          # reset by run()
         # telemetry: self._sink is None when disabled, so every emission
-        # site below is a single falsy check — the disabled path runs the
-        # exact pre-telemetry host loop (no event objects, no indirection)
+        # site below is a single falsy check — the disabled path builds no
+        # event objects (the profiler spans are separate and always on)
         self.tel = telemetry
         if telemetry is None:
             self._sink = None
@@ -608,10 +617,37 @@ class ContinuousBatchingEngine:
     def step(self, now: float | None = None,
              deadline: float | None = None) -> bool:
         """Admit + advance every prefilling slot one chunk (one batched
-        dispatch) + one K-tick decode block. ``deadline``: next timed
+        dispatch) + one K-tick decode block, each phase in its profiler
+        span (``SPAN_NAMES``, in order). ``deadline``: next timed
         arrival while a slot is free (caps the horizon — see
         ``_tick_horizon``). Returns False when nothing was left to do."""
         now = (time.perf_counter() - self._t0) if now is None else now
+        with span("serve.admit") as sp:
+            sp.note(admitted=self._admit(now))
+
+        if self.sched.prefilling:
+            self._advance_prefills()
+
+        if not self.active.any():
+            return self.sched.pending()
+
+        blk_idx = self.decode_dispatches
+        with span("serve.decode", block=blk_idx) as dec:
+            toks, k, live_slots, t_dispatch = self._dispatch_block(
+                blk_idx, now, deadline)
+            dec.note(k=k, rows=len(live_slots))
+        with span("serve.sync", block=blk_idx) as sync:
+            rows = np.asarray(toks)              # [K, n_slots]; the ONE sync
+            self.host_syncs += 1
+        with span("serve.retire", block=blk_idx) as sp:
+            sp.note(emitted=self._replay_block(
+                rows, k, live_slots, blk_idx, t_dispatch, dec.t0, sync.t1))
+        return True
+
+    def _admit(self, now: float) -> int:
+        """Step-boundary control, admission, and source-KV ingest for the
+        newly admitted (the ``serve.admit`` span). Returns how many were
+        admitted."""
         if self._draining or self._cancels or self._n_deadlined:
             self._enforce_control(now)
         newly = self.sched.admit(now)
@@ -633,16 +669,15 @@ class ContinuousBatchingEngine:
                                   detail="errored: source-KV ingest failed")
                     continue
                 self._acquire_source(st)
+        return len(newly)
 
-        if self.sched.prefilling:
-            self._advance_prefills()
-
-        if not self.active.any():
-            return self.sched.pending()
-
+    def _dispatch_block(self, blk_idx: int, now: float,
+                        deadline: float | None):
+        """Choose the horizon and launch one ``decode_multi`` block (the
+        ``serve.decode`` span). Returns (tokens on device, K, the live
+        slots at dispatch, the dispatch's ``perf_counter`` time)."""
         k = self._tick_horizon(now, deadline)
         live_slots = np.flatnonzero(self.active)     # rows at dispatch time
-        blk_idx = self.decode_dispatches
         poison = None
         if self.faults is not None:
             d = self.faults.take("tick_delay", block=blk_idx)
@@ -691,15 +726,23 @@ class ContinuousBatchingEngine:
                 jnp.asarray(self.serial), jnp.asarray(self.emitted), poison)
         self.decode_dispatches += 1
         self.dispatches += 1
-        rows = np.asarray(toks)                  # [K, n_slots]; the ONE sync
-        self.host_syncs += 1
+        return toks, k, live_slots, t_dispatch
+
+    def _replay_block(self, rows: np.ndarray, k: int, live_slots, blk_idx: int,
+                      t_dispatch: float, t_open: float,
+                      t_synced: float) -> int:
+        """Per-tick bookkeeping replayed from the synced ``[K, n_slots]``
+        block: tokens, retirements and their slot releases, quarantines,
+        the block's event and gauges, the auditor (the ``serve.retire``
+        span). ``t_open``/``t_synced``: ``perf_counter`` times at which the
+        ``serve.decode`` span opened and the ``serve.sync`` span closed.
+        Returns the tokens emitted."""
         # the block's tokens all became available at this one sync; stamps
         # inside the block are attributed by even subdivision of its wall
         # span (itl_source: "subdivided" in report())
-        now_blk = time.perf_counter() - self._t0
+        now_blk = t_synced - self._t0
         blk_start = t_dispatch - self._t0
-        span = now_blk - blk_start
-        per_tick = span / k
+        per_tick = (now_blk - blk_start) / k
         self._tick_s = (per_tick if self._tick_s == 0.0
                         else 0.5 * self._tick_s + 0.5 * per_tick)
         emitted_blk = 0
@@ -728,7 +771,7 @@ class ContinuousBatchingEngine:
             extra = {"quarantined": quarantined} if quarantined else {}
             self._sink(
                 "decode_block", t=now_blk, block=blk_idx, k=k,
-                dur=round(span, 6), emitted=emitted_blk,
+                dur=round(t_synced - t_open, 6), emitted=emitted_blk,
                 parked=issued - emitted_blk,
                 slots=[int(s) for s in live_slots],
                 serials=[int(self.serial[s]) for s in live_slots],
@@ -737,7 +780,7 @@ class ContinuousBatchingEngine:
             self._sample_gauges(now_blk, blk_idx, k, issued - emitted_blk)
         if self.auditor is not None:
             self.auditor.maybe_check(self)
-        return True
+        return emitted_blk
 
     def _sample_gauges(self, t: float, block: int, k: int,
                        parked: int) -> None:
@@ -840,19 +883,19 @@ class ContinuousBatchingEngine:
         the [V] logits)."""
         states = list(self.sched.prefilling)
         blk_idx = self.prefill_dispatches
-        t_dispatch = time.perf_counter()
-        logits, offs, sizes = self._prefill_chunk(
-            [(st.slot, st.request.prompt, st.prefilled) for st in states])
-        self.prefill_dispatches += 1
-        self.dispatches += 1
-        self.prefill_chunks += len(states)
+        with span("serve.prefill", block=blk_idx, rows=len(states)) as sp:
+            logits, offs, sizes = self._prefill_chunk(
+                [(st.slot, st.request.prompt, st.prefilled)
+                 for st in states])
+            self.prefill_dispatches += 1
+            self.dispatches += 1
+            self.prefill_chunks += len(states)
         if self._sink is not None:
             # one slice per advanced slot, sharing the batched dispatch's
             # host-side span (the program itself retires asynchronously —
             # its device time is hidden inside the next blocking sync)
-            t_done = time.perf_counter()
-            dur = round(t_done - t_dispatch, 6)
-            t_ev = t_done - self._t0
+            dur = round(sp.t1 - sp.t0, 6)
+            t_ev = sp.t1 - self._t0
             for i, st in enumerate(states):
                 self._sink("prefill_chunk", t=t_ev, rid=st.rid,
                            slot=st.slot, serial=self._serials.get(st.rid),
@@ -863,30 +906,36 @@ class ContinuousBatchingEngine:
             st.prefilled = min(st.prefilled + self.chunk, len(prompt))
             if st.prefilled < len(prompt):
                 continue   # non-final chunk: logits row never leaves device
-            # final chunk: commit the slot, sample the first token on device
-            self.cache = self._finalize(self.cache, jnp.int32(st.slot),
-                                        len(prompt))
-            self.dispatches += 1
-            self.sched.start_decoding(st)
-            self.serial[st.slot] = self._serials.pop(st.rid)
-            self.budget[st.slot] = st.request.max_new_tokens
-            tok0 = int(self._prefill_pick(logits[i],
-                                          jnp.int32(self.serial[st.slot])))
-            self.dispatches += 1
-            self.host_syncs += 1
-            t_tok0 = time.perf_counter() - self._t0
-            # admit -> first-token wall per chunk (includes the decode
-            # blocks interleaved between chunks — the realistic under-load
-            # cost the predicted-TTFT gate needs); host float math only
-            per_chunk = (max(0.0, t_tok0 - st.t_admit)
-                         / max(1, math.ceil(len(prompt) / self.chunk)))
-            self._chunk_s = (per_chunk if self._chunk_s == 0.0
-                             else 0.5 * self._chunk_s + 0.5 * per_chunk)
-            if self._sink is not None:
-                self._sink("first_token", t=t_tok0, rid=st.rid,
-                           slot=st.slot, serial=int(self.serial[st.slot]),
-                           token=tok0)
-            self._emit(st, tok0, t_tok0)
+            serial = self._serials.pop(st.rid)
+            with span("serve.first_token", slot=st.slot, serial=serial):
+                self._first_token(st, serial, logits[i])
+
+    def _first_token(self, st: RequestState, serial: int,
+                     logits_row: jax.Array) -> None:
+        """A prompt's final chunk landed: commit the slot, sample its first
+        token on device, and emit it (the ``serve.first_token`` span)."""
+        prompt = st.request.prompt
+        self.cache = self._finalize(self.cache, jnp.int32(st.slot),
+                                    len(prompt))
+        self.dispatches += 1
+        self.sched.start_decoding(st)
+        self.serial[st.slot] = serial
+        self.budget[st.slot] = st.request.max_new_tokens
+        tok0 = int(self._prefill_pick(logits_row, jnp.int32(serial)))
+        self.dispatches += 1
+        self.host_syncs += 1
+        t_tok0 = time.perf_counter() - self._t0
+        # admit -> first-token wall per chunk (includes the decode
+        # blocks interleaved between chunks — the realistic under-load
+        # cost the predicted-TTFT gate needs); host float math only
+        per_chunk = (max(0.0, t_tok0 - st.t_admit)
+                     / max(1, math.ceil(len(prompt) / self.chunk)))
+        self._chunk_s = (per_chunk if self._chunk_s == 0.0
+                         else 0.5 * self._chunk_s + 0.5 * per_chunk)
+        if self._sink is not None:
+            self._sink("first_token", t=t_tok0, rid=st.rid,
+                       slot=st.slot, serial=serial, token=tok0)
+        self._emit(st, tok0, t_tok0)
 
     def _emit(self, state: RequestState, token: int, now: float) -> None:
         # ``now``: the token's attributed timestamp — exact for prefill

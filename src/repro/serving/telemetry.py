@@ -1,5 +1,5 @@
 """Serving telemetry: structured lifecycle events, per-block engine gauges,
-and mergeable log-bucket latency histograms.
+mergeable log-bucket latency histograms, and the engine's profiler spans.
 
 The continuous engine's end-of-run ``report()`` answers *what* happened
 (aggregate throughput, dispatch counts); this module answers *where the
@@ -13,16 +13,29 @@ recover), and the event stream converts to Chrome/Perfetto trace-event
 format (:mod:`repro.serving.trace`) so prefill and decode dispatches render
 as one timeline lane per slot.
 
-Design constraints, in priority order:
+Two layers, one definition of each phase:
 
-* **Zero overhead when disabled.** The engine holds ``telemetry=None`` by
+* **Profiler spans, always on.** The engine wraps each phase of a step in
+  :class:`span` (names in :data:`SPAN_NAMES`), a
+  ``jax.profiler.TraceAnnotation``. Without a profiler session a span costs
+  one annotation object and records nothing; under ``jax.profiler.trace``
+  (an operator's capture of a live server, or a benchmark's traced window)
+  the spans land on the profiler's own clock beside the device's programs,
+  so device idle time can be charged to the phase the host was in.
+* **Lifecycle events, opt-in.** The engine holds ``telemetry=None`` by
   default and every emission site is guarded (``if self._sink``), so the
-  disabled path runs the exact pre-telemetry host loop — byte-identical
-  tokens, no event objects, no callable indirection (tested in
-  ``tests/test_telemetry.py``).
-* **Events are host-side only.** Nothing here touches device code: an
-  event records what the host already knew at a dispatch or sync site, so
-  enabling telemetry cannot perturb compiled programs or token streams.
+  disabled path builds no event objects — byte-identical tokens (tested in
+  ``tests/test_telemetry.py``). When a sink is attached, ``decode_block``
+  and ``prefill_chunk`` take their ``dur`` from the same span boundaries.
+
+Further constraints:
+
+* **Host-side only.** Nothing here touches device code: an event or span
+  records what the host already knew at a dispatch or sync site, so
+  enabling telemetry or profiling cannot perturb compiled programs or
+  token streams. (The device programs carry their own ``jax.named_scope``
+  names, :data:`repro.models.transformer.SCOPE_NAMES`; those are op
+  metadata, not host work.)
 * **Bounded memory for latency stats.** :class:`LogHistogram` replaces the
   unbounded sorted-list percentiles: fixed log-spaced buckets, O(1) insert,
   mergeable across engines / runs, percentiles exact to within one bucket
@@ -66,15 +79,36 @@ kind                    emitted when
 Every event carries ``t`` (engine-clock seconds) and, where meaningful,
 ``rid`` (request id), ``slot``, ``serial`` (admission serial) and ``block``
 (decode/prefill dispatch index); kind-specific fields ride in ``data``.
+
+Span taxonomy (:data:`SPAN_NAMES`, one ``step()``; arguments in brackets):
+
+=====================  ======================================================
+span                   covers
+=====================  ======================================================
+``serve.admit``        control enforcement, ``sched.admit``, source ingest
+                       [``admitted``]
+``serve.prefill``      the chunk arrays and the ``prefill_chunks_batched``
+                       dispatch [``block``, ``rows``]
+``serve.first_token``  one finished prompt: ``finalize_slot``, the first
+                       token's pick and its sync [``slot``, ``serial``]
+``serve.decode``       the tick horizon, the inputs, the ``decode_multi``
+                       dispatch [``block``, ``k``, ``rows``]
+``serve.sync``         the block's one host sync [``block``]
+``serve.retire``       the block's replay: tokens, retirements, slot
+                       releases, gauges, auditor [``block``, ``emitted``]
+=====================  ======================================================
 """
 from __future__ import annotations
 
 import json
 import math
+import time
 from collections import Counter
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import IO, Iterable
+
+from jax.profiler import TraceAnnotation
 
 LIFECYCLE_KINDS = (
     "enqueue", "reject", "admit", "backfill",
@@ -84,6 +118,39 @@ LIFECYCLE_KINDS = (
     "shed", "degrade", "abort", "error_retire", "fault", "drain",
 )
 EVENT_KINDS = frozenset(LIFECYCLE_KINDS) | {"gauges"}
+
+# Profiler spans of one engine step, in the order a step opens them; each
+# closes before the next opens. ``serve.first_token`` repeats once per row
+# whose prompt finished in the step's prefill. Distinct from any caller's
+# own annotations (a benchmark's ``engine.step`` wraps the whole step).
+SPAN_NAMES = ("serve.admit", "serve.prefill", "serve.first_token",
+              "serve.decode", "serve.sync", "serve.retire")
+
+
+class span:
+    """One engine phase as a profiler span: ``with span("serve.decode",
+    block=3, k=8, rows=4) as sp: ...``. Arguments are ints the engine
+    already holds; :meth:`note` adds those known only at the end. ``t0``/
+    ``t1`` are the ``perf_counter`` boundaries, which the lifecycle events
+    reuse for their ``dur``. Records nothing unless a profiler session is
+    active."""
+
+    __slots__ = ("_ann", "t0", "t1")
+
+    def __init__(self, name: str, **args: int):
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "span":
+        self.t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        self.t1 = time.perf_counter()
+
+    def note(self, **args: int) -> None:
+        self._ann.set_metadata(**args)
 
 
 @dataclass(slots=True)
