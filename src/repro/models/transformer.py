@@ -9,7 +9,11 @@ cross-attention layers (every Nth layer) scan over *groups* of
 Decode (the paper's workload) maintains a KV cache ``[L, B, Smax, Hkv, Dh]``;
 keys are cached *post-RoPE* (paper §IV-C) and the query/key rotation for the
 new token uses the incremental Eq. 11 recurrence carried in the cache
-(``rope_mode="incremental"``) or direct cos/sin (``"direct"``).
+(``rope_mode="incremental"``) or direct cos/sin (``"direct"``). The decode
+layer loop does not slice and restack the cache: the whole stack (and an
+int8 cache's scale planes) rides the loop's carry, each layer writes its new
+token into it in place at ``(layer, row, position)``, and attention reads
+that layer back out of the carry.
 """
 from __future__ import annotations
 
@@ -570,65 +574,43 @@ class TransformerLM:
         return cache
 
     @staticmethod
-    def _write_kv(kc: jax.Array, vc: jax.Array, k: jax.Array, v: jax.Array,
-                  lengths: jax.Array, active: jax.Array | None = None):
-        """kc/vc: [B, Smax, Hkv, Dh]; k/v: [B, Hkv, Dh] written at per-row
-        position ``lengths`` (mod ring size — a full-context cache never
-        wraps; a ring cache overwrites the slot that just left the window).
+    def _write_token(c: jax.Array, layer: jax.Array, x: jax.Array,
+                     pos: jax.Array, active: jax.Array | None = None
+                     ) -> jax.Array:
+        """Write each row's new token into layer ``layer`` of a stacked
+        cache: K/V ``[L, B, Smax, Hkv, Dh]`` with ``x`` [B, Hkv, Dh], or an
+        int8 cache's bf16 scale plane ``[L, B, Hkv, Smax]`` (position last)
+        with ``x`` [B, Hkv]. Row ``b`` lands at position ``pos[b]`` mod the
+        position axis (a full-context cache never wraps; a ring cache
+        overwrites the slot that just left the window). It is one scatter
+        of B tokens into the buffer the layer loop carries, so the update
+        happens in place and nothing else of the stack moves.
 
         ``active``: optional [B] bool **per-slot write mask** — rows with
         ``active=False`` rewrite their old value (an in-place no-op). This
         is the ragged-decode parking mechanism for ring caches: a ring has
         no dead tail row to park on (every slot is, or will wrap into, a
         live window position), so a parked write must not move data at
-        all. Full caches park on the reserved tail row instead and pass
-        ``active=None``."""
-        r = kc.shape[1]
-        if active is None:
-            def upd(c, x, l):
-                return jax.lax.dynamic_update_slice(c, x[None], (l % r, 0, 0))
-            kc = jax.vmap(upd)(kc, k, lengths)
-            vc = jax.vmap(upd)(vc, v, lengths)
-            return kc, vc
+        all. Full caches park on the reserved tail row instead, whose write
+        target already encodes the parking, and pass ``active=None``."""
+        rows = jnp.arange(x.shape[0])
+        if c.ndim == 5:                                  # K/V
+            at = (layer, rows, pos % c.shape[2])
+        else:                                            # scale plane
+            at = (layer, rows, slice(None), pos % c.shape[3])
+        x = x.astype(c.dtype)
+        if active is not None:
+            x = jnp.where(active.reshape(-1, *(1,) * (x.ndim - 1)), x, c[at])
+        return c.at[at].set(x, indices_are_sorted=True, unique_indices=True)
 
-        def upd_masked(c, x, l, a):
-            old = jax.lax.dynamic_slice(c, (l % r, 0, 0), (1, *c.shape[1:]))
-            return jax.lax.dynamic_update_slice(
-                c, jnp.where(a, x[None], old), (l % r, 0, 0))
-        kc = jax.vmap(upd_masked)(kc, k, lengths, active)
-        vc = jax.vmap(upd_masked)(vc, v, lengths, active)
-        return kc, vc
-
-    @staticmethod
-    def _write_kv_scales(ksc: jax.Array, vsc: jax.Array, ks: jax.Array,
-                         vs: jax.Array, lengths: jax.Array,
-                         active: jax.Array | None = None):
-        """Scale twin of :meth:`_write_kv` for the int8 cache: ksc/vsc
-        [B, Hkv, Smax] bf16 scale planes; ks/vs [B, Hkv] per-head scales of
-        the new token, written at ``lengths % Smax`` on the position axis
-        with the **same** parking semantics (``active=None`` writes
-        unconditionally — full caches park on the reserved tail row whose
-        write target already encodes the parking; ``active`` is the ring
-        per-slot rewrite-in-place mask)."""
-        ks = ks.astype(ksc.dtype)
-        vs = vs.astype(vsc.dtype)
-        r = ksc.shape[-1]
-        if active is None:
-            def upd(c, x, l):
-                return jax.lax.dynamic_update_slice(c, x[:, None], (0, l % r))
-            return jax.vmap(upd)(ksc, ks, lengths), \
-                jax.vmap(upd)(vsc, vs, lengths)
-
-        def upd_masked(c, x, l, a):
-            old = jax.lax.dynamic_slice(c, (0, l % r), (c.shape[0], 1))
-            return jax.lax.dynamic_update_slice(
-                c, jnp.where(a, x[:, None], old), (0, l % r))
-        return jax.vmap(upd_masked)(ksc, ks, lengths, active), \
-            jax.vmap(upd_masked)(vsc, vs, lengths, active)
-
-    def _decode_self_attn(self, p: Params, h: jax.Array, kc, vc,
-                          cache: Cache, active: jax.Array | None = None,
-                          ksc=None, vsc=None):
+    def _decode_self_attn(self, p: Params, h: jax.Array, kv: Cache,
+                          layer: jax.Array, cache: Cache,
+                          active: jax.Array | None = None):
+        """Self-attention of layer ``layer`` for the new token. ``kv`` is
+        the whole self-attention cache stack (``k``, ``v`` and, for an int8
+        cache, ``k_scale``/``v_scale``); the new token is written into it
+        in place and attention reads layer ``layer`` back out of it.
+        Returns ``(out, kv)``."""
         cfg = self.cfg
         b, d = h.shape
         dh = cfg.resolved_head_dim
@@ -657,29 +639,38 @@ class TransformerLM:
             # their discarded KV write on the reserved tail row and attend a
             # 1-token stub — the batch keeps its static shape while slot
             # membership changes (serving/slot_pool.py reserves the tail)
-            write_at = jnp.where(active, cache["len"], kc.shape[1] - 1)
+            write_at = jnp.where(active, cache["len"], kv["k"].shape[2] - 1)
             attn_len = jnp.where(active, cache["len"] + 1, 1)
+        kv = dict(kv)
         with jax.named_scope("kv_write"):
-            if ksc is not None:
+            if "k_scale" in kv:
                 # int8 cache: quantize the new token's K/V over Dh per head
                 # — the write parks/wraps exactly like the fp path, and the
                 # scale plane parks with it so released rows stay
                 # (0, scale 0)
                 k, k_s = quantize_kv(k)
                 v, v_s = quantize_kv(v)
-                ksc, vsc = self._write_kv_scales(ksc, vsc, k_s, v_s,
-                                                 write_at, write_mask)
-            kc, vc = self._write_kv(kc, vc, k.astype(kc.dtype),
-                                    v.astype(vc.dtype), write_at, write_mask)
+                kv["k_scale"] = self._write_token(kv["k_scale"], layer, k_s,
+                                                  write_at, write_mask)
+                kv["v_scale"] = self._write_token(kv["v_scale"], layer, v_s,
+                                                  write_at, write_mask)
+            kv["k"] = self._write_token(kv["k"], layer, k, write_at,
+                                        write_mask)
+            kv["v"] = self._write_token(kv["v"], layer, v, write_at,
+                                        write_mask)
         with jax.named_scope("attention"):
-            out = attn_lib.decode_attention(q, kc, vc, attn_len,
+            read = {name: jax.lax.dynamic_index_in_dim(a, layer,
+                                                       keepdims=False)
+                    for name, a in kv.items()}
+            out = attn_lib.decode_attention(q, read["k"], read["v"], attn_len,
                                             impl=cfg.decode_impl,
                                             window=cfg.window, ring=ring,
                                             block_size=cfg.attn_block or 512,
-                                            k_scale=ksc, v_scale=vsc)
+                                            k_scale=read.get("k_scale"),
+                                            v_scale=read.get("v_scale"))
         with jax.named_scope("attn_out"):
             out = linear(p, "wo", out.reshape(b, -1))
-        return out, kc, vc, ksc, vsc
+        return out, kv
 
     def _decode_cross_attn(self, p: Params, h: jax.Array, ck, cv,
                            source_len: jax.Array) -> jax.Array:
@@ -723,20 +714,21 @@ class TransformerLM:
         out = linear(p, "wo", out.reshape(b, -1))
         return jnp.tanh(p["gate"]).astype(h.dtype) * out
 
-    def _decode_block(self, bp: Params, slices: dict, x: jax.Array,
-                      cache: Cache, active: jax.Array | None = None
-                      ) -> tuple[jax.Array, dict]:
-        """One self block at decode time. ``slices`` holds this layer's cache
-        tensors; returns updated slices as scan ys."""
+    def _decode_block(self, bp: Params, kv: Cache, layer: jax.Array,
+                      slices: dict, x: jax.Array, cache: Cache,
+                      active: jax.Array | None = None
+                      ) -> tuple[jax.Array, Cache, dict]:
+        """One self block at decode time. ``kv`` is the whole self-attention
+        cache stack, carried by the layer loop and updated in place at layer
+        ``layer``; ``slices`` holds this layer's other state (Mamba state,
+        read-only source KV). Returns ``(x, kv, new)``, ``new`` being the
+        updated Mamba state as scan ys."""
         cfg = self.cfg
         new = {}
         with jax.named_scope("qkv"):
             h = rms_norm(x, bp["ln1"], cfg.norm_eps)
-        attn_out, new["k"], new["v"], ksc, vsc = self._decode_self_attn(
-            bp["attn"], h, slices["k"], slices["v"], cache, active,
-            slices.get("k_scale"), slices.get("v_scale"))
-        if ksc is not None:
-            new["k_scale"], new["v_scale"] = ksc, vsc
+        attn_out, kv = self._decode_self_attn(bp["attn"], h, kv, layer, cache,
+                                              active)
         if cfg.family == "hybrid":
             st = mamba_lib.MambaState(conv=slices["mamba_conv"],
                                       ssm=slices["mamba_ssm"])
@@ -763,7 +755,6 @@ class TransformerLM:
             x = x + self._decode_cross_attn(bp["cross"], hc, slices["cross_k"],
                                             slices["cross_v"],
                                             cache["source_len"])
-            new["cross_k"], new["cross_v"] = slices["cross_k"], slices["cross_v"]
         with jax.named_scope("mlp"):
             h2 = rms_norm(x, bp["ln2"], cfg.norm_eps)
             if cfg.n_experts:
@@ -777,7 +768,7 @@ class TransformerLM:
                     gated=cfg.gated_mlp)
             else:
                 y = mlp_apply(bp["ffn"], h2, cfg.act, cfg.gated_mlp)
-            return x + y, new
+            return x + y, kv, new
 
     def decode_step(self, params: Params, tokens: jax.Array,
                     cache: Cache, active: jax.Array | None = None
@@ -794,7 +785,7 @@ class TransformerLM:
         unchanged via ``jnp.where`` selects. Ring KV caches (``kv_ring``
         SWA configs) have no parking row either — every ring slot is, or
         wraps into, a live window position — so their inactive rows park
-        via a per-slot write *mask* (:meth:`_write_kv` ``active=``), the
+        via a per-slot write *mask* (:meth:`_write_token` ``active=``), the
         row rewriting its old value in place. Cross-attention source KV
         needs no parking at all: the pooled ``src_k/src_v`` entries are
         read-only at decode (each row reads its ``src_index`` entry masked
@@ -812,15 +803,20 @@ class TransformerLM:
 
         n_cross = self._n_cross_groups()
 
-        def step(x, xs):
-            bp, slices = xs
-            x, new = self._decode_block(bp, slices, x, cache, active)
-            return x, new
+        # the self-attention KV stack rides the layer loop's carry whole and
+        # each layer writes its new token into it in place; only per-layer
+        # state that is not the stack (Mamba state, source KV) is scanned
+        def step(carry, xs):
+            x, kv = carry
+            bp, layer, slices = xs
+            x, kv, new = self._decode_block(bp, kv, layer, slices, x, cache,
+                                            active)
+            return (x, kv), new
 
-        self_slices = {"k": cache["k"], "v": cache["v"]}
-        if "k_scale" in cache:
-            self_slices["k_scale"] = cache["k_scale"]
-            self_slices["v_scale"] = cache["v_scale"]
+        kv = {key: cache[key] for key in ("k", "v", "k_scale", "v_scale")
+              if key in cache}
+        layers = jnp.arange(cache["k"].shape[0])
+        self_slices = {}
         if cfg.family == "hybrid":
             self_slices["mamba_conv"] = cache["mamba_conv"]
             self_slices["mamba_ssm"] = cache["mamba_ssm"]
@@ -838,7 +834,9 @@ class TransformerLM:
             # nothing (matches prefill/forward with source=None)
 
         if not n_cross:
-            x, new = layer_scan(step, x, (params["blocks"], self_slices), unroll=cfg.unroll_layers)
+            (x, kv), new = layer_scan(step, (x, kv),
+                                      (params["blocks"], layers, self_slices),
+                                      unroll=cfg.unroll_layers)
         else:
             group = cfg.cross_attn_every
             n_self_per = group - 1
@@ -853,9 +851,10 @@ class TransformerLM:
                 # its FFN; only the (gated) cross-attention term vanishes
                 cross_xs, cross_mode = (), "none"
 
-            def group_step(x, xs):
-                gp, gs, cp, *ckv = xs
-                x, new = layer_scan(step, x, (gp, gs), unroll=cfg.unroll_layers)
+            def group_step(carry, xs):
+                gp, gl, gs, cp, *ckv = xs
+                (x, kv), new = layer_scan(step, carry, (gp, gl, gs),
+                                          unroll=cfg.unroll_layers)
                 h = rms_norm(x, cp["ln1"], cfg.norm_eps)
                 if cross_mode == "pooled":
                     x = x + self._decode_cross_attn_pooled(
@@ -868,25 +867,21 @@ class TransformerLM:
                                                     cache["source_len"])
                 h2 = rms_norm(x, cp["ln2"], cfg.norm_eps)
                 x = x + mlp_apply(cp["ffn"], h2, cfg.act, cfg.gated_mlp)
-                return x, new
+                return (x, kv), new
 
-            gp = jax.tree.map(
-                lambda a: a.reshape(n_cross, n_self_per, *a.shape[1:]),
-                params["blocks"])
-            gs = jax.tree.map(
-                lambda a: a.reshape(n_cross, n_self_per, *a.shape[1:]),
-                self_slices)
-            x, new = layer_scan(group_step, x,
-                                (gp, gs, params["cross_blocks"], *cross_xs),
-                                unroll=cfg.unroll_layers)
+            # layer index g * n_self_per + i: the self layer's place in the
+            # stack the cache is laid out by
+            grouped = lambda a: a.reshape(n_cross, n_self_per, *a.shape[1:])
+            (x, kv), new = layer_scan(
+                group_step, (x, kv),
+                (jax.tree.map(grouped, params["blocks"]), grouped(layers),
+                 jax.tree.map(grouped, self_slices), params["cross_blocks"],
+                 *cross_xs),
+                unroll=cfg.unroll_layers)
             new = jax.tree.map(
                 lambda a: a.reshape(n_cross * n_self_per, *a.shape[2:]), new)
 
-        cache = dict(cache)
-        for key in ("k", "v", "k_scale", "v_scale",
-                    "mamba_conv", "mamba_ssm"):
-            if key in new:
-                cache[key] = new[key]
+        cache = dict(cache, **kv, **new)
         cache["len"] = cache["len"] + (1 if active is None
                                        else active.astype(jnp.int32))
         cache = self._advance_rope(cache)

@@ -16,7 +16,7 @@ Layout contract with :meth:`TransformerLM.decode_step`'s ragged form:
   ``capacity = max_len - 1``. Ring KV caches (``kv_ring`` SWA configs) have
   no parkable dead row — every ring slot is, or wraps into, a live window
   position — so their inactive slots park via a per-slot **write mask**
-  (the row rewrites its old value in place; ``TransformerLM._write_kv``
+  (the row rewrites its old value in place; ``TransformerLM._write_token``
   ``active=``). The tail reservation still prices admission for rings:
   ``capacity`` bounds a request's *position* budget (``cache['len']`` /
   RoPE state run over absolute positions), which is ``max_len``-scaled even

@@ -9,6 +9,7 @@ lives in the shared harness of ``test_serving_conformance.py``."""
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -123,3 +124,74 @@ def test_batched_prefill_single_dispatch(dense_model):
     agg = eng.run(trace)["aggregate"]
     assert agg["prefill_chunks"] == 12          # 4 prompts x 3 chunks
     assert agg["prefill_dispatches"] == 3       # one per step, not per slot
+
+
+# the ragged families of the conformance suite, and the int8 cache on a ring
+IN_PLACE_ARCHS = ["qwen3_8b", "qwen3_8b+w4a8", "h2o_danube_1p8b+ring",
+                  "h2o_danube_1p8b+ring+w4a8", "hymba_1p5b",
+                  "llama32_vision_90b", "olmoe_1b_7b", "whisper_small"]
+
+
+def _bits(a) -> np.ndarray:
+    a = np.asarray(a)
+    return a.view(np.uint16) if a.dtype.itemsize == 2 else a
+
+
+@pytest.mark.parametrize("arch", IN_PLACE_ARCHS)
+def test_decode_step_writes_only_each_rows_token(arch):
+    """One ragged ``decode_step`` changes each self-attention layer's K/V
+    (and an int8 cache's scale planes) only where a row's new token lands:
+    an active row's write position in every layer, and an inactive row's
+    reserved tail row in a full cache. An inactive ring row does not change
+    at all. Every other element is its input, bit for bit."""
+    cfg, model, params = _build(arch)
+    ring = bool(cfg.kv_ring and cfg.window)
+    slots = 4
+    source = cfg.source_len if cfg.cross_attn_every else None
+    cache = model.init_cache(slots, 256 if ring else 64, source,
+                             n_sources=slots if source else None)
+    size = cache["k"].shape[2]
+    lengths = (np.array([size + 2, 3, 2 * size - 1, size + 9]) if ring
+               else np.array([5, 3, 17, 9]))
+    active = np.array([True, False, True, False])
+    rng = np.random.default_rng(0)
+    kv_keys = [k for k in ("k", "v", "k_scale", "v_scale") if k in cache]
+    for key in kv_keys + [k for k in ("src_k", "src_v") if k in cache]:
+        a = cache[key]
+        fill = (rng.integers(-127, 128, a.shape) if a.dtype == jnp.int8
+                else rng.uniform(0.5, 1.5, a.shape) if "scale" in key
+                else rng.standard_normal(a.shape))
+        cache[key] = jnp.asarray(fill, a.dtype)
+    if source:
+        cache["src_len"] = jnp.full((slots,), source // 2, jnp.int32)
+        cache["src_index"] = jnp.arange(slots, dtype=jnp.int32)
+    cache["len"] = jnp.asarray(lengths, jnp.int32)
+    before = {k: _bits(cache[k]) for k in kv_keys}
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, slots), jnp.int32)
+    logits, out = jax.jit(model.decode_step)(params, tokens, cache,
+                                             jnp.asarray(active))
+    assert np.isfinite(np.asarray(logits)).all()
+
+    for key in kv_keys:
+        new, old = _bits(out[key]), before[key]
+        pos_last = "scale" in key             # [L, B, Hkv, S]: position last
+        assert new.shape == old.shape, key
+        may_change = np.zeros(old.shape, bool)
+        for b in range(slots):
+            if active[b]:
+                at = lengths[b] % size
+            elif ring:
+                continue
+            else:
+                at = size - 1                 # the reserved tail row
+            if pos_last:
+                may_change[:, b, :, at] = True
+            else:
+                may_change[:, b, at] = True
+        assert np.array_equal(new[~may_change], old[~may_change]), key
+        for b in np.flatnonzero(active):      # the token landed in every layer
+            at = lengths[b] % size
+            for layer in range(old.shape[0]):
+                got = new[layer, b, :, at] if pos_last else new[layer, b, at]
+                was = old[layer, b, :, at] if pos_last else old[layer, b, at]
+                assert not np.array_equal(got, was), (key, layer, b)
